@@ -266,6 +266,7 @@ def _multidevice_case() -> dict:
             f.write(_SUBPROCESS)
         env = dict(os.environ)
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        env["JAX_PLATFORMS"] = "cpu"      # never the chip its parent holds
         env["PYTHONPATH"] = os.path.join(
             os.path.dirname(__file__), "..", "src")
         proc = subprocess.run([sys.executable, script], env=env,

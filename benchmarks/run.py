@@ -167,6 +167,11 @@ def main() -> None:
         validate_traces(only)
         return
 
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+
     from benchmarks import (bench_balance, bench_blocking,
                             bench_concurrency, bench_distributed,
                             bench_numeric, bench_refactorize, bench_robust,

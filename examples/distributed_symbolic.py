@@ -31,8 +31,9 @@ def main() -> None:
     print(f"matrix: n={a.n} nnz={a.nnz}; devices: {len(jax.devices())}")
 
     # 1. SPMD path: interleaved sources over the device mesh
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((len(jax.devices()),), ("src",))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((len(jax.devices()),), ("src",),
+                         axis_types=(AxisType.Auto,))
     res = distributed_symbolic(graph, mesh, policy="interleave")
     print(f"distributed: balance ratio {res['balance_ratio']:.2f} "
           f"across {res['n_shards']} shard(s)")

@@ -38,7 +38,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import SHARD_MAP_NOCHECK_KW, shard_map
 from repro.core.gsofa import (
     SymbolicGraph, fill_masks, fixpoint_impl, init_labels, row_counts,
 )
@@ -98,14 +97,13 @@ def make_distributed_counts(mesh: Mesh, graph_n: int, *, backend: str = "ell",
     spec_rep = P()
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(spec_src, spec_rep),
         out_specs=(spec_src, spec_src, spec_src, P(axes)),
         # the while_loop carry mixes device-varying labels with replicated
         # scalars (trip counts differ per device by design) — disable the
-        # varying-manual-axes (check_rep on older jax) check rather than
-        # pcast every carry leaf
-        **SHARD_MAP_NOCHECK_KW,
+        # varying-manual-axes check rather than pcast every carry leaf
+        check_vma=False,
     )
     def body(srcs_mat, graph):
         return _local_body(srcs_mat, graph, max_iters, backend)
@@ -193,10 +191,10 @@ def make_distributed_chunk_step(mesh: Mesh, graph_n: int, *,
     spec_rep = P()
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(spec_src, spec_rep),
         out_specs=(spec_mat, spec_mat, spec_src, spec_src, spec_src, P(axes)),
-        **SHARD_MAP_NOCHECK_KW,     # per-device while_loop trip counts differ
+        check_vma=False,            # per-device while_loop trip counts differ
     )
     def body(srcs_mat, graph):
         srcs = srcs_mat.reshape(-1)                       # (C,) local chunk

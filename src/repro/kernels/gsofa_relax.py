@@ -40,16 +40,15 @@ def _relax_kernel(prop_ref, adj_ref, out_ref, *, block_u: int, u_chunk: int):
     prop = prop_ref[...]            # (Bs, Bu)
     adj = adj_ref[...]              # (Bu, Bv)
 
-    def chunk_body(c, acc):
-        # Process u_chunk rows of the adjacency tile at a time: the 3-D
-        # broadcast (Bs, u_chunk, Bv) stays small enough for VREGs/VMEM.
-        p = jax.lax.dynamic_slice_in_dim(prop, c * u_chunk, u_chunk, axis=1)
-        a = jax.lax.dynamic_slice_in_dim(adj, c * u_chunk, u_chunk, axis=0)
-        masked = jnp.where(a[None, :, :] != 0, p[:, :, None], inf)
-        return jnp.minimum(acc, jnp.min(masked, axis=1))
-
+    # Process u_chunk rows of the adjacency tile at a time: the 3-D
+    # broadcast (Bs, u_chunk, Bv) stays small enough for VREGs/VMEM.  The
+    # loop is unrolled with static slices (Mosaic lowers no dynamic_slice).
     acc = jnp.full_like(out_ref, inf)
-    acc = jax.lax.fori_loop(0, block_u // u_chunk, chunk_body, acc)
+    for c in range(0, block_u, u_chunk):
+        p = prop[:, c:c + u_chunk]
+        a = adj[c:c + u_chunk, :]
+        masked = jnp.where(a[None, :, :] != 0, p[:, :, None], inf)
+        acc = jnp.minimum(acc, jnp.min(masked, axis=1))
     out_ref[...] = jnp.minimum(out_ref[...], acc)
 
 

@@ -31,13 +31,24 @@ def _pad_to(x: jax.Array, axis: int, mult: int, value) -> jax.Array:
     return jnp.pad(x, widths, constant_values=value)
 
 
+def _host_padded(x, shape) -> jax.Array:
+    """float32 ``x`` zero-padded up to ``shape``, padded on the host.
+
+    The panel GEMMs see hundreds of distinct logical shapes per plan but only
+    a handful of padded ones; padding and casting with numpy before the
+    transfer keeps the device compiling one program per padded shape, not a
+    pad, cast and slice program per logical shape."""
+    x = np.asarray(x, np.float32)
+    return jnp.asarray(np.pad(x, [(0, t - d) for d, t in zip(x.shape, shape)]))
+
+
 def padded_gemm_shape(m, k, n, *, block_m: int = 128, block_n: int = 128,
                       block_k: int = 128):
     """Padded ``(M, K, N)`` that ``panel_update`` actually dispatches for a
     logical ``m x k @ k x n`` update.
 
-    Mirrors the block-sizing in :func:`panel_update` (sublane multiples of 8
-    on M, lane multiples of 128 on K/N) so cost models can charge the
+    The block-sizing :func:`panel_update` pads with (sublane multiples of 8
+    on M, lane multiples of 128 on K/N), shared so cost models can charge the
     explicit-zero MXU work instead of the logical shape.  Accepts scalars or
     numpy arrays (vectorised over candidate partitions); zero-sized operands
     stay zero since those dispatches are skipped entirely.
@@ -88,7 +99,7 @@ def column_fingerprints(rel: jax.Array, src: jax.Array, m1: jax.Array,
 
     Pads the source axis to ``block_s`` (invalid rows) and the vertex axis to
     ``block_v`` (labels clamped high so padded columns read as empty), packs
-    the per-source lanes into the (8, S) meta layout, and slices back.
+    the per-source lanes into the (S, 8) meta layout, and slices back.
     """
     if interpret is None:
         interpret = not _on_tpu()
@@ -97,11 +108,9 @@ def column_fingerprints(rel: jax.Array, src: jax.Array, m1: jax.Array,
     big = jnp.int32(jnp.iinfo(jnp.int32).max)
     rel_p = _pad_to(_pad_to(rel, 0, block_s, big), 1, block_v, big)
     sp = rel_p.shape[0]
-    meta = jnp.zeros((8, sp), dtype=jnp.int32)
-    meta = meta.at[0, :s].set(src.astype(jnp.int32))
-    meta = meta.at[1, :s].set(m1.astype(jnp.int32))
-    meta = meta.at[2, :s].set(m2.astype(jnp.int32))
-    meta = meta.at[3, :s].set(valid.astype(jnp.int32))
+    lanes = jnp.stack([x.astype(jnp.int32) for x in (src, m1, m2, valid)],
+                      axis=1)                               # (s, 4)
+    meta = jnp.pad(lanes, ((0, sp - s), (0, 4)))            # (sp, 8)
     out = supernode_fp_pallas(rel_p, meta, block_s=block_s, block_v=block_v,
                               interpret=interpret)
     return out[:3, :v]
@@ -112,35 +121,31 @@ def column_fingerprints_ref(rel: jax.Array, src: jax.Array, m1: jax.Array,
     return _ref.supernode_fp_ref(rel, src, m1, m2, valid)
 
 
-def panel_update(acc: jax.Array, l_panel: jax.Array, u_panel: jax.Array, *,
-                 block_m: int = 128, block_n: int = 128, block_k: int = 128,
-                 interpret: bool | None = None) -> jax.Array:
+def panel_update(acc: np.ndarray, l_panel: np.ndarray, u_panel: np.ndarray,
+                 *, block_m: int = 128, block_n: int = 128, block_k: int = 128,
+                 interpret: bool | None = None) -> np.ndarray:
     """(M, N) supernodal panel update ``acc - l_panel @ u_panel``; see
-    panel_update.py.  Pads all three operands with zeros (zero products leave
-    the padded region inert) and slices back.  float32 — the numeric driver
-    (repro.numeric) keeps its float64 path on numpy and routes the heavy GEMM
-    here on TPU."""
+    panel_update.py.  Pads all three operands with zeros on the host (zero
+    products leave the padded region inert) and slices back.  float32 — the
+    numeric layer (repro.numeric) keeps its float64 path on numpy and
+    routes the heavy GEMM here on TPU."""
     if interpret is None:
         interpret = not _on_tpu()
-    acc = jnp.asarray(acc, jnp.float32)
-    l_panel = jnp.asarray(l_panel, jnp.float32)
-    u_panel = jnp.asarray(u_panel, jnp.float32)
+    acc = np.asarray(acc, np.float32)
     m, n = acc.shape
     k = l_panel.shape[1]
-    if m == 0 or n == 0:
+    if m == 0 or n == 0 or k == 0:
         return acc
-    if k == 0:
-        return acc
-    block_m = min(block_m, max(8, ((m + 7) // 8) * 8))
-    block_n = min(block_n, max(128, ((n + 127) // 128) * 128))
-    block_k = min(block_k, max(128, ((k + 127) // 128) * 128))
-    acc_p = _pad_to(_pad_to(acc, 0, block_m, 0.0), 1, block_n, 0.0)
-    l_p = _pad_to(_pad_to(l_panel, 0, block_m, 0.0), 1, block_k, 0.0)
-    u_p = _pad_to(_pad_to(u_panel, 0, block_k, 0.0), 1, block_n, 0.0)
-    out = panel_update_pallas(acc_p, l_p, u_p, block_m=block_m,
-                              block_n=block_n, block_k=block_k,
-                              interpret=interpret)
-    return out[:m, :n]
+    mp, kp, np_ = padded_gemm_shape(m, k, n, block_m=block_m,
+                                    block_n=block_n, block_k=block_k)
+    # a dim shorter than its block was padded to a block of its own size
+    out = panel_update_pallas(_host_padded(acc, (mp, np_)),
+                              _host_padded(l_panel, (mp, kp)),
+                              _host_padded(u_panel, (kp, np_)),
+                              block_m=min(block_m, mp),
+                              block_n=min(block_n, np_),
+                              block_k=min(block_k, kp), interpret=interpret)
+    return np.asarray(out)[:m, :n]
 
 
 def panel_update_ref(acc, l_panel, u_panel):
@@ -149,10 +154,10 @@ def panel_update_ref(acc, l_panel, u_panel):
                                  jnp.asarray(u_panel, jnp.float32))
 
 
-def panel_update_batched(acc: jax.Array, l_panel: jax.Array,
-                         u_panel: jax.Array, *, block_m: int = 128,
+def panel_update_batched(acc: np.ndarray, l_panel: np.ndarray,
+                         u_panel: np.ndarray, *, block_m: int = 128,
                          block_n: int = 128, block_k: int = 128,
-                         interpret: bool | None = None) -> jax.Array:
+                         interpret: bool | None = None) -> np.ndarray:
     """(B, M, N) stacked supernodal panel updates in ONE kernel launch; see
     ``panel_update_batched_pallas``.  Pads the trailing dims with the exact
     block sizes the per-panel ``panel_update`` wrapper would pick for
@@ -162,27 +167,25 @@ def panel_update_batched(acc: jax.Array, l_panel: jax.Array,
 
     if interpret is None:
         interpret = not _on_tpu()
-    acc = jnp.asarray(acc, jnp.float32)
-    l_panel = jnp.asarray(l_panel, jnp.float32)
-    u_panel = jnp.asarray(u_panel, jnp.float32)
+    acc = np.asarray(acc, np.float32)
     b, m, n = acc.shape
     k = l_panel.shape[2]
     if b == 0 or m == 0 or n == 0 or k == 0:
         return acc
-    block_m = min(block_m, max(8, ((m + 7) // 8) * 8))
-    block_n = min(block_n, max(128, ((n + 127) // 128) * 128))
-    block_k = min(block_k, max(128, ((k + 127) // 128) * 128))
-    acc_p = _pad_to(_pad_to(acc, 1, block_m, 0.0), 2, block_n, 0.0)
-    l_p = _pad_to(_pad_to(l_panel, 1, block_m, 0.0), 2, block_k, 0.0)
-    u_p = _pad_to(_pad_to(u_panel, 1, block_k, 0.0), 2, block_n, 0.0)
-    out = panel_update_batched_pallas(acc_p, l_p, u_p, block_m=block_m,
-                                      block_n=block_n, block_k=block_k,
+    mp, kp, np_ = padded_gemm_shape(m, k, n, block_m=block_m,
+                                    block_n=block_n, block_k=block_k)
+    out = panel_update_batched_pallas(_host_padded(acc, (b, mp, np_)),
+                                      _host_padded(l_panel, (b, mp, kp)),
+                                      _host_padded(u_panel, (b, kp, np_)),
+                                      block_m=min(block_m, mp),
+                                      block_n=min(block_n, np_),
+                                      block_k=min(block_k, kp),
                                       interpret=interpret)
-    return out[:, :m, :n]
+    return np.asarray(out)[:, :m, :n]
 
 
 def panel_update_systems(acc, l_panel, u_panel, *,
-                         interpret: bool | None = None) -> jax.Array:
+                         interpret: bool | None = None) -> np.ndarray:
     """Stacked panel updates with arbitrary leading batch axes — the
     many-matrix tier's GEMM entry point (DESIGN.md §14).
 
@@ -194,9 +197,7 @@ def panel_update_systems(acc, l_panel, u_panel, *,
     dispatch — and every slice stays bitwise-identical to its own
     per-panel ``panel_update`` call (the vmap per-slice grid parity that
     the within-plan segment batching relies on)."""
-    acc = jnp.asarray(acc, jnp.float32)
-    l_panel = jnp.asarray(l_panel, jnp.float32)
-    u_panel = jnp.asarray(u_panel, jnp.float32)
+    acc, l_panel, u_panel = (np.asarray(x) for x in (acc, l_panel, u_panel))
     lead = acc.shape[:-2]
     m, n = acc.shape[-2:]
     k = l_panel.shape[-1]

@@ -21,9 +21,10 @@ sublane pad is free at int32 tile granularity); row 0 accumulates with ``+``,
 row 1 with wrapping ``+``, row 2 with ``^`` — all associative, so the S-axis
 grid accumulation is race-free by construction.
 
-Tiling constraints: last dim multiples of 128, second-to-last multiples of 8
-(int32 VREG shape 8 x 128).  VMEM per step: ``Bs*Bv + 8*Bs + 8*Bv`` int32
-elements; defaults (8, 512) -> ~20 KB << 16 MB.
+Tiling constraints: last dim multiples of 128 (or the whole array dim, as
+for the 8-lane meta block), second-to-last multiples of 8 (int32 VREG shape
+8 x 128).  VMEM per step: ``Bs*Bv + 8*Bs + 8*Bv`` int32 elements; defaults
+(8, 512) -> ~20 KB << 16 MB.
 """
 from __future__ import annotations
 
@@ -40,9 +41,9 @@ def _fp_kernel(rel_ref, meta_ref, out_ref, *, block_s: int, block_v: int):
     rel_ref:  (Bs, Bv) int32 — offset-free labels: maxId, or n+1 when the
               label is uninitialized/stale (precomputed by the ops.py wrapper
               so no SMEM scalar is needed in the hot loop).
-    meta_ref: (8, Bs) int32 — per-source lanes: row 0 = source id, row 1 =
-              mix1(source), row 2 = mix2(source), row 3 = 1 for real rows
-              (0 for batch padding); rows 4..7 are sublane padding.
+    meta_ref: (Bs, 8) int32 — per-source lanes: lane 0 = source id, lane 1 =
+              mix1(source), lane 2 = mix2(source), lane 3 = 1 for real rows
+              (0 for batch padding); lanes 4..7 are padding.
     out_ref:  (8, Bv) int32 — row 0 count, row 1 hash-sum, row 2 hash-xor.
     """
     @pl.when(pl.program_id(1) == 0)
@@ -50,11 +51,11 @@ def _fp_kernel(rel_ref, meta_ref, out_ref, *, block_s: int, block_v: int):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     rel = rel_ref[...]                                   # (Bs, Bv)
-    meta = meta_ref[...]                                 # (8, Bs)
-    src = meta[0, :][:, None]                            # (Bs, 1)
-    m1 = meta[1, :][:, None]
-    m2 = meta[2, :][:, None]
-    valid = meta[3, :][:, None]
+    meta = meta_ref[...]                                 # (Bs, 8)
+    src = meta[:, 0:1]                                   # (Bs, 1)
+    m1 = meta[:, 1:2]
+    m2 = meta[:, 2:3]
+    valid = meta[:, 3:4]
 
     col = (pl.program_id(0) * block_v
            + jax.lax.broadcasted_iota(jnp.int32, rel.shape, 1))
@@ -65,13 +66,10 @@ def _fp_kernel(rel_ref, meta_ref, out_ref, *, block_s: int, block_v: int):
     cnt = jnp.sum(mask.astype(jnp.int32), axis=0)        # (Bv,)
     hsum = jnp.sum(jnp.where(mask, jnp.broadcast_to(m1, rel.shape), 0), axis=0)
     xor_terms = jnp.where(mask, jnp.broadcast_to(m2, rel.shape), 0)
-
-    def xor_row(i, acc):
-        return acc ^ jax.lax.dynamic_index_in_dim(
-            xor_terms, i, axis=0, keepdims=False)
-
-    hxor = jax.lax.fori_loop(0, block_s, xor_row,
-                             jnp.zeros((rel.shape[1],), jnp.int32))
+    # static unroll: Mosaic has no dynamic row index into a value
+    hxor = xor_terms[0]
+    for i in range(1, block_s):
+        hxor = hxor ^ xor_terms[i]
 
     row = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 0)
     cur = out_ref[...]
@@ -90,11 +88,11 @@ def supernode_fp_pallas(rel: jax.Array, meta: jax.Array, *, block_s: int = 8,
 
     rel:  (S, V) int32 — ``maxId`` of each (source, vertex), with
           uninitialized/stale labels clamped to n+1 (> any column id).
-    meta: (8, S) int32 — see ``_fp_kernel``.
+    meta: (S, 8) int32 — see ``_fp_kernel``.
     Shapes must be padded to block multiples by the wrapper (ops.py).
     """
     s, v = rel.shape
-    assert meta.shape == (8, s), (meta.shape, rel.shape)
+    assert meta.shape == (s, 8), (meta.shape, rel.shape)
     assert s % block_s == 0 and v % block_v == 0
 
     grid = (v // block_v, s // block_s)
@@ -104,7 +102,7 @@ def supernode_fp_pallas(rel: jax.Array, meta: jax.Array, *, block_s: int = 8,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_s, block_v), lambda j, i: (i, j)),
-            pl.BlockSpec((8, block_s), lambda j, i: (0, i)),
+            pl.BlockSpec((block_s, 8), lambda j, i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((8, block_v), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((8, v), jnp.int32),

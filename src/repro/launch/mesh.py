@@ -18,42 +18,21 @@ GSoFa shards *sources* over every axis flattened (paper's interleave, §V).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-# jax.sharding.AxisType landed after 0.4.x; on older jax every axis is
-# implicitly Auto, so the compat builders below simply drop the argument.
-from repro.compat import AXIS_TYPE as _AXIS_TYPE
-
-
-def compat_make_mesh(axis_shapes: tuple, axis_names: tuple) -> Mesh:
-    """jax.make_mesh with Auto axis types across jax versions."""
-    if _AXIS_TYPE is not None:
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=(_AXIS_TYPE.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
-
-
-def compat_abstract_mesh(axis_shapes: tuple, axis_names: tuple):
-    """AbstractMesh (device-less) with Auto axis types across jax versions."""
-    from jax.sharding import AbstractMesh
-
-    if _AXIS_TYPE is not None:
-        return AbstractMesh(axis_shapes, axis_names,
-                            axis_types=(_AXIS_TYPE.Auto,) * len(axis_names))
-    return AbstractMesh(tuple(zip(axis_names, axis_shapes)))
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1) -> Mesh:
     """Mesh over whatever devices exist (tests / examples on CPU)."""
     n = len(jax.devices())
     assert n % model == 0, (n, model)
-    return compat_make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 FLAT_AXIS = "shards"
@@ -73,21 +52,22 @@ def make_flat_mesh(n_devices: int | None = None) -> Mesh:
     *panels*) over the flattened device space, so a single axis is the
     whole story at any scale.
 
-    ``n_devices=None`` takes every visible device through the compat
-    builder — the same call yields a 1-device mesh on a laptop and an
-    8-device mesh under ``XLA_FLAGS=--xla_force_host_platform_device_count
-    =8``, which is exactly how the conformance tier runs one code path at
-    every device count.  An explicit ``n_devices`` takes a prefix of
+    ``n_devices=None`` takes every visible device — the same call yields a
+    1-device mesh on a laptop and an 8-device mesh under
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8``, which is
+    exactly how the conformance tier runs one code path at every device
+    count.  An explicit ``n_devices`` takes a prefix of
     ``jax.devices()`` (must not exceed what exists).
     """
     avail = jax.devices()
     if n_devices is None:
-        return compat_make_mesh((len(avail),), (FLAT_AXIS,))
+        n_devices = len(avail)
     if not 1 <= n_devices <= len(avail):
         raise ValueError(f"n_devices={n_devices} out of range for "
                          f"{len(avail)} visible device(s)")
     if n_devices == len(avail):
-        return compat_make_mesh((n_devices,), (FLAT_AXIS,))
+        return jax.make_mesh((n_devices,), (FLAT_AXIS,),
+                             axis_types=(AxisType.Auto,))
     import numpy as np
 
     return Mesh(np.asarray(avail[:n_devices]), (FLAT_AXIS,))

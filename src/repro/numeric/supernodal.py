@@ -417,8 +417,12 @@ def factor_on_store(a: Optional[CSRMatrix], values: np.ndarray,
             and backend == "kernel"):
         import jax
 
-        if len(jax.devices()) >= placement.n_devices:
-            devices = jax.devices()[:placement.n_devices]
+        if len(jax.devices()) < placement.n_devices:
+            raise ValueError(
+                f"placement spans {placement.n_devices} devices but only "
+                f"{len(jax.devices())} are visible; re-place the plan "
+                f"(plan.place()) for this host")
+        devices = jax.devices()[:placement.n_devices]
 
     n_updates = 0
     gemm_flops = 0

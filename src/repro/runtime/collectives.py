@@ -37,7 +37,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
 
 _RING_OPS = ("add", "xor", "max")
 
@@ -80,7 +79,7 @@ def _ring_allreduce_local(x: jax.Array, axis_name: str, *,
     if compress and op != "add":
         raise ValueError(f"int8 compression only supports op='add', "
                          f"got {op!r}")
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     if n == 1:
         return x
@@ -142,7 +141,7 @@ def make_ring_allreduce(mesh: Mesh, axis: str, *, compress: bool = False,
     n = mesh.shape[axis]
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=P(axis, None), out_specs=P(axis, None))
     def body(x_local):                       # (1, k) on each device
         flat = x_local.reshape(-1)

@@ -112,10 +112,7 @@ class DynamicScheduler:
 
     @staticmethod
     def _ready(flight: _InFlight) -> bool:
-        try:
-            return all(o.is_ready() for o in flight.outs)
-        except AttributeError:  # older jax: block (still correct, less async)
-            return True
+        return all(o.is_ready() for o in flight.outs)
 
     def run(self, *, drop_devices_after: Optional[int] = None,
             join_devices_after: Optional[int] = None) -> dict:

@@ -55,8 +55,8 @@ def test_elastic_reshard(tmp_path):
     """Save under one mesh, restore under another sharding (elastic)."""
     t = {"w": jnp.arange(32, dtype=jnp.float32).reshape(4, 8)}
     save_checkpoint(str(tmp_path), 1, t)
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((1,), ("data",))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     from jax.sharding import NamedSharding, PartitionSpec as P
     sh = {"w": NamedSharding(mesh, P("data", None))}
     from repro.checkpoint import reshard_checkpoint
@@ -70,8 +70,9 @@ def test_restart_reproduces_uninterrupted_run(tmp_path):
     """Train 4 steps straight vs 2 steps -> checkpoint -> restore -> 2 steps."""
     cfg = get_config("smollm-135m").reduced()
     shape = ShapeConfig("s", 16, 2, "train")
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     step = make_train_step(cfg, mesh, shape, dtype=jnp.float32, donate=False)
 
     def batches():

@@ -11,7 +11,7 @@ counts, pattern, panel partition, factors, solutions, and
 pickle-roundtrip factors — plus cross-process pickling (a plan analyzed
 on 8 devices refactorizes bitwise in the 1-device parent).
 
-The property-based half (via ``_hypothesis_compat``) pins the fingerprint
+The property-based half (hypothesis) pins the fingerprint
 merge algebra the tier relies on: per-shard partial fingerprints over any
 source sharding fold to exactly the single-shard fingerprints, and the
 T2/T3 supernode boundaries are invariant under the shard count.
@@ -26,7 +26,7 @@ import sys
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.distributed import assign_sources, ownership_mask
 from repro.core.gsofa import prepare_graph
@@ -191,6 +191,7 @@ def conformance(tmp_path_factory):
     for count in DEVICE_COUNTS:
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={count}"
+        env["JAX_PLATFORMS"] = "cpu"      # never the chip its parent holds
         env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
                                          "src")
         plan_path = tmp / f"plan_{count}.pkl"
@@ -249,6 +250,24 @@ def test_placement_spreads_panels(count, conformance):
     for name, rec in got.items():
         expect = min(count, rec["max_level_width"])
         assert rec["devices_with_panels"] == expect, (count, name)
+
+
+def test_kernel_placement_beyond_visible_devices_raises():
+    """A kernel-backend placement over more devices than this process sees
+    is an error, never a silent fall back onto the default device."""
+    import jax
+
+    import repro
+    from repro.sparse.matrices import bordered_block_diagonal
+    from repro.sparse.numeric import generic_values_csr
+
+    a = bordered_block_diagonal(160, block=16, border=8, seed=0)
+    want = len(jax.devices()) + 1
+    plan = repro.analyze(a, repro.LUOptions(numeric_backend="kernel"))
+    plan.place(want)
+    assert plan.placement.n_devices == want
+    with pytest.raises(ValueError, match="are visible"):
+        plan.factorize(generic_values_csr(a))
 
 
 def test_dynamic_runtime_matches_reference_on_8_devices(conformance,
@@ -488,6 +507,7 @@ def blocked_conformance(tmp_path_factory):
     script = tmp / "blocked.py"
     script.write_text(_BLOCKED_SCRIPT)
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"      # never the chip its parent holds
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     plan_path = tmp / "plans.pkl"
     proc = subprocess.run(

@@ -7,7 +7,7 @@ Chain of evidence:
 """
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.fill2 import fill2_dense
 from repro.core.gsofa import prepare_graph, dense_pattern, gsofa_batch

@@ -25,8 +25,8 @@ out = {}
 
 # --- ring all-reduce ---
 from repro.runtime.collectives import make_ring_allreduce
-from repro.launch.mesh import compat_make_mesh
-mesh1 = compat_make_mesh((8,), ("x",))
+from jax.sharding import AxisType
+mesh1 = jax.make_mesh((8,), ("x",), axis_types=(AxisType.Auto,))
 x = jnp.asarray(np.random.default_rng(0).standard_normal((8, 500)), jnp.float32)
 want = np.asarray(x).sum(0)
 got = np.asarray(make_ring_allreduce(mesh1, "x")(x))
@@ -80,6 +80,7 @@ def results(tmp_path_factory):
     path = tmp_path_factory.mktemp("md") / "script.py"
     path.write_text(_SCRIPT)
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"      # never the chip its parent holds
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     r = subprocess.run([sys.executable, str(path)], capture_output=True,
                        text=True, timeout=1200, env=env,

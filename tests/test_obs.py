@@ -297,6 +297,7 @@ def parity(tmp_path_factory):
     path = tmp_path_factory.mktemp("obs") / "parity.py"
     path.write_text(_PARITY_SCRIPT)
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"      # never the chip its parent holds
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     r = subprocess.run([sys.executable, str(path)], capture_output=True,
                        text=True, timeout=1200, env=env,

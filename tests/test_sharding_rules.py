@@ -5,7 +5,7 @@ import math
 
 import jax
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import get_config
@@ -16,8 +16,9 @@ from repro.train.steps import param_specs
 @pytest.fixture(scope="module")
 def mesh():
     # host has 1 device: an abstract mesh stands in for the 16x16 pod
-    from repro.launch.mesh import compat_abstract_mesh
-    return compat_abstract_mesh((16, 16), ("data", "model"))
+    from jax.sharding import AbstractMesh, AxisType
+    return AbstractMesh((16, 16), ("data", "model"),
+                        axis_types=(AxisType.Auto,) * 2)
 
 
 def _canon(spec):

@@ -14,8 +14,9 @@ from repro.train.steps import make_train_step
 
 
 def _mesh():
-    from repro.launch.mesh import compat_make_mesh
-    return compat_make_mesh((1, 1), ("data", "model"))
+    from jax.sharding import AxisType
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def test_adamw_minimizes_quadratic():
