@@ -189,7 +189,8 @@ class LUOptions:
     # -- observability (DESIGN.md §12): record phase spans + counters for
     # this plan's analyze/factorize calls (repro.obs); plans/factors gain a
     # ``stats`` summary tree.  Off by default — the disabled path is a
-    # module-level boolean check, so it cannot perturb timings.
+    # module-level boolean check, so it cannot perturb timings.  A running
+    # jax.profiler session gets the spans without it (DESIGN.md §12.1).
     trace: bool = False
 
     def __post_init__(self):
@@ -312,12 +313,14 @@ class LUFactorization:
         way.  ``SolveResult.factor_s`` is 0.0 — the factorization time
         lives on this object's ``factor_s``."""
         opts = self.plan.options
-        return _solve(
-            self.plan.a, b, values=self.values, num=self.num,
-            refine_iters=(opts.refine_iters if refine_iters is None
-                          else refine_iters),
-            refine_tol=opts.refine_tol if refine_tol is None else refine_tol,
-            batched=batched, transform=self.plan.robust)
+        with _ot.ensure():
+            return _solve(
+                self.plan.a, b, values=self.values, num=self.num,
+                refine_iters=(opts.refine_iters if refine_iters is None
+                              else refine_iters),
+                refine_tol=(opts.refine_tol if refine_tol is None
+                            else refine_tol),
+                batched=batched, transform=self.plan.robust)
 
     @property
     def perturbed_pivots(self) -> int:
@@ -405,12 +408,14 @@ class BatchedLUFactorization:
         system's solution and residual history match the sequential
         ``factor.solve`` loop bitwise."""
         opts = self.plan.options
-        return _solve_batch(
-            self.plan.a, b, self.values, self.num,
-            refine_iters=(opts.refine_iters if refine_iters is None
-                          else refine_iters),
-            refine_tol=opts.refine_tol if refine_tol is None else refine_tol,
-            transform=self.plan.robust)
+        with _ot.ensure():
+            return _solve_batch(
+                self.plan.a, b, self.values, self.num,
+                refine_iters=(opts.refine_iters if refine_iters is None
+                              else refine_iters),
+                refine_tol=(opts.refine_tol if refine_tol is None
+                            else refine_tol),
+                transform=self.plan.robust)
 
 
 @dataclasses.dataclass

@@ -299,7 +299,9 @@ def distributed_multisource(graph: SymbolicGraph, mesh: Mesh, *,
     owned = ownership_mask(srcs_mat)
     per = srcs_mat.shape[1]
     concurrency = max(1, min(concurrency, per))
-    step = make_distributed_chunk_step(mesh, n, backend=backend, axes=axes)
+    with _ot.span("shard_step_build"):
+        step = make_distributed_chunk_step(mesh, n, backend=backend,
+                                           axes=axes)
 
     l_counts = np.zeros(n, dtype=np.int64)
     u_counts = np.zeros(n, dtype=np.int64)
@@ -327,11 +329,8 @@ def distributed_multisource(graph: SymbolicGraph, mesh: Mesh, *,
 
     def _reduce(cols, own, outs):
         nonlocal supersteps, n_chunks
-        labels, mask, l_cnt, u_cnt, edges, iters = outs
-        labels = np.asarray(labels)
-        mask = np.asarray(mask)
-        l_cnt, u_cnt = np.asarray(l_cnt), np.asarray(u_cnt)
-        edges = np.asarray(edges)
+        labels, mask, l_cnt, u_cnt, edges, iters = _ot.fetch(
+            tuple(outs), "shard chunk outputs")
         with _ot.span("host_reduce"):
             for d in range(n_shards):
                 keep = own[d]
@@ -346,11 +345,10 @@ def distributed_multisource(graph: SymbolicGraph, mesh: Mesh, *,
                     on_shard_mask(d, mask[d], cols[d])
         # per-shard while_loop trip counts differ by design; the step's
         # wall-clock is the slowest shard's count
-        supersteps += int(np.asarray(iters).max())
+        supersteps += int(iters.max())
         n_chunks += 1
         if _ot.ENABLED:
-            _om.registry().observe("fixpoint.iterations",
-                                   int(np.asarray(iters).max()))
+            _om.registry().observe("fixpoint.iterations", int(iters.max()))
             _om.registry().count("fixpoint.chunks")
         if meter is not None:
             meter.update(n_chunks, total_steps)
@@ -366,7 +364,10 @@ def distributed_multisource(graph: SymbolicGraph, mesh: Mesh, *,
     for start in range(0, per, concurrency):
         with _ot.span("fixpoint_chunk"):
             cols, own = _inputs(start)
-            outs = step(jnp.asarray(cols), graph)
+            # one sharded program; its first call in each analyze traces,
+            # lowers and loads it
+            with _ot.span("chunk_dispatch"):
+                outs = step(_ot.put(cols, "chunk sources"), graph)
         if pending is not None:
             t0 = time.perf_counter()
             with _ot.span("overlap"):

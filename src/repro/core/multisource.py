@@ -173,44 +173,48 @@ def run_multisource(graph: SymbolicGraph, *, concurrency: int = 64,
 
     meter = _om.ProgressMeter(on_progress) if on_progress is not None else None
     for ci, chunk in enumerate(chunks):
-        srcs = jnp.asarray(chunk.srcs)
+        srcs = _ot.put(chunk.srcs, "chunk sources")
         if combined:
             groups = [np.arange(len(chunk.srcs))]
         else:
             groups = [np.array([i]) for i in range(chunk.n_real)]
         for g in groups:
             with _ot.span("fixpoint_chunk"):
-                gs = srcs[jnp.asarray(g)]
-                if bubble and chunk.width < n:
-                    offset = 0
-                    view = _chunk_view(graph, chunk.width)
-                    nbrs = graph.out_ell[gs]
-                    labels0 = init_labels(view, gs, nbrs=nbrs)
-                    res = gsofa.gsofa_batch(view, gs, backend="ell",
-                                            labels0=labels0,
-                                            max_iters=chunk.width + 2)
-                    mask = _finalize_bubble(graph, res.labels, gs, 0,
-                                            chunk.width)
-                    v_ids = jnp.arange(n, dtype=jnp.int32)
-                    l_cnt = jnp.sum(mask & (v_ids[None, :] < gs[:, None]),
-                                    axis=1)
-                    u_cnt = jnp.sum(mask & (v_ids[None, :] > gs[:, None]),
-                                    axis=1)
-                else:
-                    offset = 0
-                    labels0 = None
-                    if arena is not None and combined:
-                        offset = arena.next_window()
-                        labels0 = init_labels(graph, gs, offset=offset,
-                                              stale_buf=arena.buf)
-                    res = gsofa.gsofa_batch(graph, gs, backend=backend,
-                                            labels0=labels0, offset=offset)
-                    if arena is not None and combined:
-                        arena.buf = res.labels
-                    mask = None
-                    if collect_masks or on_mask is not None:
-                        mask = fill_masks(res.labels, gs, offset)
-                    l_cnt, u_cnt = row_counts(res.labels, gs, offset)
+                # the chunk's device programs, dispatched one by one (label
+                # set-up, the fixpoint, fill masks, row counts)
+                with _ot.span("chunk_dispatch"):
+                    gs = srcs[jnp.asarray(g)]
+                    if bubble and chunk.width < n:
+                        offset = 0
+                        view = _chunk_view(graph, chunk.width)
+                        nbrs = graph.out_ell[gs]
+                        labels0 = init_labels(view, gs, nbrs=nbrs)
+                        res = gsofa.gsofa_batch(view, gs, backend="ell",
+                                                labels0=labels0,
+                                                max_iters=chunk.width + 2)
+                        mask = _finalize_bubble(graph, res.labels, gs, 0,
+                                                chunk.width)
+                        v_ids = jnp.arange(n, dtype=jnp.int32)
+                        l_cnt = jnp.sum(
+                            mask & (v_ids[None, :] < gs[:, None]), axis=1)
+                        u_cnt = jnp.sum(
+                            mask & (v_ids[None, :] > gs[:, None]), axis=1)
+                    else:
+                        offset = 0
+                        labels0 = None
+                        if arena is not None and combined:
+                            offset = arena.next_window()
+                            labels0 = init_labels(graph, gs, offset=offset,
+                                                  stale_buf=arena.buf)
+                        res = gsofa.gsofa_batch(graph, gs, backend=backend,
+                                                labels0=labels0,
+                                                offset=offset)
+                        if arena is not None and combined:
+                            arena.buf = res.labels
+                        mask = None
+                        if collect_masks or on_mask is not None:
+                            mask = fill_masks(res.labels, gs, offset)
+                        l_cnt, u_cnt = row_counts(res.labels, gs, offset)
 
                 if on_chunk is not None:
                     on_chunk(res.labels, chunk.srcs[np.asarray(g)], offset)
@@ -218,16 +222,18 @@ def run_multisource(graph: SymbolicGraph, *, concurrency: int = 64,
                     on_mask(mask, chunk.srcs[np.asarray(g)])
                 real = np.asarray(g) < chunk.n_real
                 real_idx = chunk.srcs[np.asarray(g)[real]]
-                l_counts[real_idx] = np.asarray(l_cnt)[real]
-                u_counts[real_idx] = np.asarray(u_cnt)[real]
-                edge_checks[real_idx] = np.asarray(res.edge_checks)[real]
-                conv_iters[real_idx] = np.asarray(res.conv_iter)[real]
-                supersteps += int(res.iters)
+                l_h, u_h, edges_h, conv_h, iters = _ot.fetch(
+                    (l_cnt, u_cnt, res.edge_checks, res.conv_iter,
+                     res.iters), "chunk counts")
+                l_counts[real_idx] = l_h[real]
+                u_counts[real_idx] = u_h[real]
+                edge_checks[real_idx] = edges_h[real]
+                conv_iters[real_idx] = conv_h[real]
+                supersteps += int(iters)
                 if collect_masks and mask is not None:
-                    masks[real_idx] = np.asarray(mask)[real]
+                    masks[real_idx] = _ot.fetch(mask, "chunk mask")[real]
                 if _ot.ENABLED:
-                    _om.registry().observe("fixpoint.iterations",
-                                           int(res.iters))
+                    _om.registry().observe("fixpoint.iterations", int(iters))
                     _om.registry().count("fixpoint.chunks")
         if meter is not None:
             meter.update(ci + 1, len(chunks))

@@ -168,7 +168,7 @@ class PatternCollector:
 
     def update(self, mask, srcs: np.ndarray) -> int:
         """Accumulate one chunk's fill mask; returns #new rows consumed."""
-        if not _ot.ENABLED:
+        if not _ot.SPANS:
             return self._update(mask, srcs)
         with _ot.span("pattern_collect"):
             return self._update(mask, srcs)
@@ -179,7 +179,7 @@ class PatternCollector:
         keep = first[~self.seen[srcs[first]]]
         if len(keep) == 0:
             return 0
-        mask = np.asarray(mask, dtype=bool)
+        mask = _ot.fetch(mask, "chunk mask").astype(bool, copy=False)
         for i in keep:
             src = int(srcs[i])
             row = np.flatnonzero(mask[i]).astype(np.int64)
@@ -364,7 +364,8 @@ def symbolic_factorize(a: CSRMatrix, *, concurrency: int = 128,
                          f"('static', 'dynamic')")
     if graph is None:
         dense_block = 128 if backend in ("dense", "kernel") else None
-        graph = prepare_graph(a, dense_block=dense_block)
+        with _ot.span("prepare_graph"):
+            graph = prepare_graph(a, dense_block=dense_block)
     if mesh is not None:
         if runtime == "dynamic":
             raise ValueError(

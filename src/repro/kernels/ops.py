@@ -15,6 +15,7 @@ from repro.kernels.gsofa_relax import minmax_relax_pallas
 from repro.kernels.panel_update import panel_update_pallas
 from repro.kernels.supernode_fp import supernode_fp_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
+from repro.obs import trace as _ot
 
 
 def _on_tpu() -> bool:
@@ -39,7 +40,8 @@ def _host_padded(x, shape) -> jax.Array:
     transfer keeps the device compiling one program per padded shape, not a
     pad, cast and slice program per logical shape."""
     x = np.asarray(x, np.float32)
-    return jnp.asarray(np.pad(x, [(0, t - d) for d, t in zip(x.shape, shape)]))
+    return _ot.put(np.pad(x, [(0, t - d) for d, t in zip(x.shape, shape)]),
+                   "panel operand")
 
 
 def padded_gemm_shape(m, k, n, *, block_m: int = 128, block_n: int = 128,
@@ -145,7 +147,7 @@ def panel_update(acc: np.ndarray, l_panel: np.ndarray, u_panel: np.ndarray,
                               block_m=min(block_m, mp),
                               block_n=min(block_n, np_),
                               block_k=min(block_k, kp), interpret=interpret)
-    return np.asarray(out)[:m, :n]
+    return _ot.fetch(out, "panel update")[:m, :n]
 
 
 def panel_update_ref(acc, l_panel, u_panel):
@@ -181,7 +183,7 @@ def panel_update_batched(acc: np.ndarray, l_panel: np.ndarray,
                                       block_n=min(block_n, np_),
                                       block_k=min(block_k, kp),
                                       interpret=interpret)
-    return np.asarray(out)[:, :m, :n]
+    return _ot.fetch(out, "panel update")[:, :m, :n]
 
 
 def panel_update_systems(acc, l_panel, u_panel, *,

@@ -32,7 +32,6 @@ from typing import List, Optional
 import numpy as np
 
 from repro.numeric.storage import CSCPattern
-from repro.obs import metrics as _om
 from repro.obs import trace as _ot
 from repro.supernodes.balance import PanelPartition, pack_panels
 
@@ -130,20 +129,8 @@ def build_placement(schedule: PanelSchedule, n_devices: int, *,
                                schedule.col_counts,
                                min(n_devices, len(members)), policy=policy)
             device_of_panel[members] = part.assignment
-        placement = PanelPlacement(n_devices=n_devices, axis=axis,
-                                   device_of_panel=device_of_panel)
-        if _ot.ENABLED and n_devices > 1:
-            # modeled per-level imbalance: max/mean packed bin weight of the
-            # LPT assignment — the planning-time counterpart of the measured
-            # segment-time imbalance factor_on_store records
-            loads = placement.level_loads(schedule)
-            reg = _om.registry()
-            for lv in range(loads.shape[0]):
-                busy = loads[lv][loads[lv] > 0]
-                if len(busy):
-                    reg.observe("placement.imbalance_modeled",
-                                float(busy.max()) / float(busy.mean()))
-        return placement
+        return PanelPlacement(n_devices=n_devices, axis=axis,
+                              device_of_panel=device_of_panel)
 
 
 @dataclasses.dataclass

@@ -435,16 +435,19 @@ def solve(a: CSRMatrix, b: np.ndarray, *, sym=None,
     b_norms = np.where(b_norms == 0.0, 1.0, b_norms)
     with _ot.span("solve"):
         x = fsolve(b)
-        res_cols = _col_residuals(matvec, x, b, b_norms)
+        with _ot.span("residual"):
+            res_cols = _col_residuals(matvec, x, b, b_norms)
         residuals = [float(res_cols.max())]
         accepted = 0
         for _ in range(max(0, refine_iters)):
             if res_cols.max() <= refine_tol:
                 break
             with _ot.span("refine"):
-                r = b - matvec(x)
+                with _ot.span("residual"):
+                    r = b - matvec(x)
                 x_try = x + fsolve(r)
-                res_try = _col_residuals(matvec, x_try, b, b_norms)
+                with _ot.span("residual"):
+                    res_try = _col_residuals(matvec, x_try, b, b_norms)
                 improve = res_try < res_cols
                 if not improve.any():
                     break              # no column improving — keep best x
@@ -679,7 +682,8 @@ def solve_batch(a: CSRMatrix, b: np.ndarray, values_batch: np.ndarray,
 
     with _ot.span("solve_batch"):
         x = fsolve(b)
-        res_cols = residuals_of(x)                       # (B, kk)
+        with _ot.span("residual"):
+            res_cols = residuals_of(x)                   # (B, kk)
         histories = [[float(res_cols[i].max())] for i in range(bsz)]
         accepted = np.zeros(bsz, dtype=np.int64)
         stopped = np.zeros(bsz, dtype=bool)
@@ -690,10 +694,12 @@ def solve_batch(a: CSRMatrix, b: np.ndarray, values_batch: np.ndarray,
             if not active.any():
                 break
             with _ot.span("refine"):
-                r = np.stack([b[i] - csr_matvec(a, values_batch[i], x[i])
-                              for i in range(bsz)])
+                with _ot.span("residual"):
+                    r = np.stack([b[i] - csr_matvec(a, values_batch[i], x[i])
+                                  for i in range(bsz)])
                 x_try = x + fsolve(r)
-                res_try = residuals_of(x_try)
+                with _ot.span("residual"):
+                    res_try = residuals_of(x_try)
                 improve = (res_try < res_cols) & active[:, None]
                 any_imp = improve.any(axis=1)
                 stopped |= active & ~any_imp   # sequential's permanent break

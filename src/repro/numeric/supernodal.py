@@ -226,22 +226,26 @@ def _factor_panel(store: PanelStore, schedule: PanelSchedule, j: int,
     produced on a row absent from the panel's structure — nonzero beyond
     roundoff means symbolic under-prediction).
     """
-    lp, b, dropped, flops = _panel_prepare(store, schedule, j, maps=maps)
+    with _ot.span("panel_prepare"):
+        lp, b, dropped, flops = _panel_prepare(store, schedule, j, maps=maps)
     if lp is not None:
         # accumulated trailing update: one GEMM over the gathered ancestor
         # L panels against the solved U rows (MXU kernel on TPU), writing
         # straight back into the packed block rows >= s
         block = store.blocks[j]
         d = int(store.diag[j])
-        acc = block[d:]
-        if backend == "kernel":
-            from repro.kernels import ops as kops
+        with _ot.span("panel_gemm"):
+            acc = block[d:]
+            if backend == "kernel":
+                from repro.kernels import ops as kops
 
-            upd = np.asarray(kops.panel_update(acc, lp, b), dtype=np.float64)
-        else:
-            upd = acc - lp @ b
-        block[d:] = upd
-    _panel_finish(store, schedule, j, piv_tol, perturb=perturb)
+                upd = np.asarray(kops.panel_update(acc, lp, b),
+                                 dtype=np.float64)
+            else:
+                upd = acc - lp @ b
+            block[d:] = upd
+    with _ot.span("panel_finish"):
+        _panel_finish(store, schedule, j, piv_tol, perturb=perturb)
     return len(schedule.ancestors[j]), flops, dropped
 
 
@@ -270,15 +274,17 @@ def _factor_segment_batched(store: PanelStore, schedule: PanelSchedule,
     out = []
     operands = {}
     groups: dict = {}
-    for j in seg:
-        j = int(j)
-        lp, b, dropped, flops = _panel_prepare(
-            store, schedule, j, maps=maps[j] if maps is not None else None)
-        out.append((j, len(schedule.ancestors[j]), flops, dropped))
-        if lp is None:
-            continue
-        operands[j] = (lp, b)
-        groups.setdefault(lp.shape + (b.shape[1],), []).append(j)
+    with _ot.span("panel_prepare"):
+        for j in seg:
+            j = int(j)
+            lp, b, dropped, flops = _panel_prepare(
+                store, schedule, j,
+                maps=maps[j] if maps is not None else None)
+            out.append((j, len(schedule.ancestors[j]), flops, dropped))
+            if lp is None:
+                continue
+            operands[j] = (lp, b)
+            groups.setdefault(lp.shape + (b.shape[1],), []).append(j)
 
     obs_on = _ot.ENABLED
     batched_calls = 0
@@ -290,32 +296,35 @@ def _factor_segment_batched(store: PanelStore, schedule: PanelSchedule,
             lp, b = operands[j]
             block = store.blocks[j]
             d = int(store.diag[j])
-            acc = block[d:]
-            if backend == "kernel":
-                from repro.kernels import ops as kops
+            with _ot.span("panel_gemm"):
+                acc = block[d:]
+                if backend == "kernel":
+                    from repro.kernels import ops as kops
 
-                upd = np.asarray(kops.panel_update(acc, lp, b),
-                                 dtype=np.float64)
-            else:
-                upd = acc - lp @ b
-            block[d:] = upd
+                    upd = np.asarray(kops.panel_update(acc, lp, b),
+                                     dtype=np.float64)
+                else:
+                    upd = acc - lp @ b
+                block[d:] = upd
             continue
         # stacked same-shape group: one dispatch covers the whole stack,
         # device-resident on the kernel backend (the segment's
         # jax.default_device context owns the transfer + launch)
-        accs = np.stack([store.blocks[j][int(store.diag[j]):] for j in js])
-        lps = np.stack([operands[j][0] for j in js])
-        bs = np.stack([operands[j][1] for j in js])
-        if backend == "kernel":
-            from repro.kernels import ops as kops
+        with _ot.span("panel_gemm"):
+            accs = np.stack([store.blocks[j][int(store.diag[j]):]
+                             for j in js])
+            lps = np.stack([operands[j][0] for j in js])
+            bs = np.stack([operands[j][1] for j in js])
+            if backend == "kernel":
+                from repro.kernels import ops as kops
 
-            upds = np.asarray(kops.panel_update_batched(accs, lps, bs),
-                              dtype=np.float64)
-        else:
-            upds = accs - np.matmul(lps, bs)
-        for bi, j in enumerate(js):
-            d = int(store.diag[j])
-            store.blocks[j][d:] = upds[bi]
+                upds = np.asarray(kops.panel_update_batched(accs, lps, bs),
+                                  dtype=np.float64)
+            else:
+                upds = accs - np.matmul(lps, bs)
+            for bi, j in enumerate(js):
+                d = int(store.diag[j])
+                store.blocks[j][d:] = upds[bi]
         batched_calls += 1
         batched_panels += len(js)
         if obs_on:
@@ -328,8 +337,9 @@ def _factor_segment_batched(store: PanelStore, schedule: PanelSchedule,
         reg.count("gemm.batched.calls", batched_calls)
         reg.count("gemm.batched.panels", batched_panels)
 
-    for j in seg:
-        _panel_finish(store, schedule, int(j), piv_tol, perturb=perturb)
+    with _ot.span("panel_finish"):
+        for j in seg:
+            _panel_finish(store, schedule, int(j), piv_tol, perturb=perturb)
     return out
 
 
@@ -700,18 +710,20 @@ def factor_batch_on_store(a: Optional[CSRMatrix], values_batch: np.ndarray,
         with _ot.span("factor_level"):
             operands = {}
             groups: dict = {}
-            for j in level:
-                j = int(j)
-                lp, b, dropped, flops = _panel_prepare_batched(
-                    bstore, schedule, j,
-                    maps=maps[j] if maps is not None else None)
-                n_updates += len(schedule.ancestors[j])
-                gemm_flops += flops
-                np.maximum(dropped_max, dropped, out=dropped_max)
-                if lp is None:
-                    continue
-                operands[j] = (lp, b)
-                groups.setdefault(lp.shape[1:] + (b.shape[2],), []).append(j)
+            with _ot.span("panel_prepare"):
+                for j in level:
+                    j = int(j)
+                    lp, b, dropped, flops = _panel_prepare_batched(
+                        bstore, schedule, j,
+                        maps=maps[j] if maps is not None else None)
+                    n_updates += len(schedule.ancestors[j])
+                    gemm_flops += flops
+                    np.maximum(dropped_max, dropped, out=dropped_max)
+                    if lp is None:
+                        continue
+                    operands[j] = (lp, b)
+                    groups.setdefault(lp.shape[1:] + (b.shape[2],),
+                                      []).append(j)
 
             for (m, k, w), js in groups.items():
                 if len(js) == 1:
@@ -719,33 +731,37 @@ def factor_batch_on_store(a: Optional[CSRMatrix], values_batch: np.ndarray,
                     j = js[0]
                     lp, b = operands[j]
                     d = int(bstore.diag[j])
-                    acc = bstore.blocks[j][:, d:]
+                    with _ot.span("panel_gemm"):
+                        acc = bstore.blocks[j][:, d:]
+                        if backend == "kernel":
+                            from repro.kernels import ops as kops
+
+                            upd = np.asarray(
+                                kops.panel_update_systems(acc, lp, b),
+                                dtype=np.float64)
+                        else:
+                            upd = acc - np.matmul(lp, b)
+                        bstore.blocks[j][:, d:] = upd
+                    continue
+                # same-shape panel group x system batch: one stacked dispatch
+                with _ot.span("panel_gemm"):
+                    accs = np.concatenate(
+                        [bstore.blocks[j][:, int(bstore.diag[j]):]
+                         for j in js])
+                    lps = np.concatenate([operands[j][0] for j in js])
+                    bs = np.concatenate([operands[j][1] for j in js])
                     if backend == "kernel":
                         from repro.kernels import ops as kops
 
-                        upd = np.asarray(
-                            kops.panel_update_systems(acc, lp, b),
+                        upds = np.asarray(
+                            kops.panel_update_systems(accs, lps, bs),
                             dtype=np.float64)
                     else:
-                        upd = acc - np.matmul(lp, b)
-                    bstore.blocks[j][:, d:] = upd
-                    continue
-                # same-shape panel group x system batch: one stacked dispatch
-                accs = np.concatenate(
-                    [bstore.blocks[j][:, int(bstore.diag[j]):] for j in js])
-                lps = np.concatenate([operands[j][0] for j in js])
-                bs = np.concatenate([operands[j][1] for j in js])
-                if backend == "kernel":
-                    from repro.kernels import ops as kops
-
-                    upds = np.asarray(
-                        kops.panel_update_systems(accs, lps, bs),
-                        dtype=np.float64)
-                else:
-                    upds = accs - np.matmul(lps, bs)
-                for gi, j in enumerate(js):
-                    d = int(bstore.diag[j])
-                    bstore.blocks[j][:, d:] = upds[gi * bsz:(gi + 1) * bsz]
+                        upds = accs - np.matmul(lps, bs)
+                    for gi, j in enumerate(js):
+                        d = int(bstore.diag[j])
+                        bstore.blocks[j][:, d:] = upds[gi * bsz:
+                                                       (gi + 1) * bsz]
                 batched_calls += 1
                 batched_panels += len(js)
                 if obs_on:
@@ -755,12 +771,13 @@ def factor_batch_on_store(a: Optional[CSRMatrix], values_batch: np.ndarray,
                     reg.count("gemm.batched.bytes",
                               8 * len(js) * bsz * (m * k + k * w + 2 * m * w))
 
-            for j in level:
-                try:
-                    _panel_finish_batched(bstore, schedule, int(j),
-                                          piv_tol_sys, perturb=pstate)
-                except ZeroPivotError as e:
-                    raise e.with_context(panel=int(j), level=li)
+            with _ot.span("panel_finish"):
+                for j in level:
+                    try:
+                        _panel_finish_batched(bstore, schedule, int(j),
+                                              piv_tol_sys, perturb=pstate)
+                    except ZeroPivotError as e:
+                        raise e.with_context(panel=int(j), level=li)
     if obs_on:
         reg = _om.registry()
         if batched_calls:

@@ -11,7 +11,9 @@ Quickstart::
     print(repro.obs.metrics.registry().snapshot()["gauges"])
 
 Disabled (the default) every instrumentation site is a module-level boolean
-check — tier-1 timings and bitwise gates are unaffected.
+check — tier-1 timings and bitwise gates are unaffected.  Under a running
+``jax.profiler`` session the spans annotate the profiler's trace as
+``repro.<name>`` instead, with no option to set.
 """
 from repro.obs import metrics, trace
 from repro.obs.metrics import (
@@ -19,8 +21,8 @@ from repro.obs.metrics import (
     roofline_report, stderr_progress,
 )
 from repro.obs.trace import (
-    SpanSummary, Tracer, device_track, disable, enable, ensure, span,
-    traced, tracer, tracing,
+    SpanSummary, Tracer, device_track, disable, enable, ensure, fetch, put,
+    span, traced, tracer, tracing,
 )
 
 __all__ = [
@@ -28,5 +30,5 @@ __all__ = [
     "MetricsRegistry", "ProgressMeter", "fraction_of_peak", "registry",
     "roofline_report", "stderr_progress",
     "SpanSummary", "Tracer", "device_track", "disable", "enable", "ensure",
-    "span", "traced", "tracer", "tracing",
+    "fetch", "put", "span", "traced", "tracer", "tracing",
 ]

@@ -14,7 +14,6 @@ fill.lu_nnz                     gauge     structural nnz(L+U) incl. diagonal
 fill.input_nnz                  gauge     nnz(A)
 supernodes.count                gauge     number of detected panels
 supernodes.size                 hist      panel widths (columns per supernode)
-placement.imbalance_modeled     hist      per-level max/mean modeled bin weight
 factor.level_imbalance_measured hist      per-level max/mean measured segment s
 fingerprint.bytes               counter   bytes moved by fingerprint updates
 fingerprint.seconds             counter   wall seconds inside those updates
@@ -22,16 +21,13 @@ gemm.flops                      counter   flops of the accumulated panel GEMMs
 gemm.bytes                      counter   analytic bytes gathered + scattered
 gemm.seconds                    counter   wall seconds of the panel sweep
 robust.perturbed_pivots         counter   tiny pivots bumped by the sweep
-robust.growth                   gauge     element growth max|L\\U|/max|A_f|
-robust.cond_estimate            gauge     Hager cond_1 estimate (-1 = inf)
 blocking.merges                 counter   supernode pairs coalesced by blocking
 blocking.panels_before          gauge     panels entering the merge pass
 blocking.panels_after           gauge     panels after structure-aware merging
-blocking.pad_entries            gauge     explicit zeros the merged blocks carry
-blocking.modeled_gain_s         gauge     modeled sweep seconds saved by merging
 tune.candidates                 counter   partitions scored by the autotune sweep
 tune.modeled_s                  gauge     modeled sweep seconds of the chosen
-tune.baseline_s                 gauge     modeled seconds of the untuned knobs
+transfer.bytes_to_host          counter   bytes read back by ``fetch``
+transfer.bytes_to_device        counter   bytes sent by ``put``
 ==============================  ========  =====================================
 
 Roofline: ``fraction_of_peak`` / ``roofline_report`` are pure functions of

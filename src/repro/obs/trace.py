@@ -1,18 +1,31 @@
 """Nested span tracing for the LU pipeline (DESIGN.md §12).
 
 Zero-overhead-when-disabled is the design contract: every instrumentation
-site in the pipeline calls ``span("name")``, and when tracing is off that
+site in the pipeline calls ``span("name")``, and when spans are off that
 call is a module-level boolean check returning a cached no-op context
 manager — no ``Span`` allocation, no ``perf_counter`` read, no lock.  The
 tier-1 bitwise gates and the committed bench ratio gates therefore see the
 instrumented code paths unchanged.
 
-When enabled (``tracing(path=...)``, ``enable()``, or
-``LUOptions(trace=True)``) the active ``Tracer`` records one *complete*
-event per span — name, start, duration, track, nesting depth — with a
-per-thread span stack (``threading.local``) so the chunk driver's worker
-threads and the per-device segment sweeps each get coherent nesting, and a
-single lock protecting only the append to the shared event list.
+Spans are live in two cases, checked once per public call (``ensure``):
+
+* tracing is on (``tracing(path=...)``, ``enable()``, or
+  ``LUOptions(trace=True)``): the active ``Tracer`` records one *complete*
+  event per span — name, start, duration, track, nesting depth — with a
+  per-thread span stack (``threading.local``) so the chunk driver's worker
+  threads and the per-device segment sweeps each get coherent nesting, and
+  a single lock protecting only the append to the shared event list;
+* a JAX profiler session was collecting when the public call began: every
+  span also opens a ``jax.profiler.TraceAnnotation`` named
+  ``repro.<name>``, with the span's keyword arguments as its metadata, so
+  the program's phases lie on the device planes' clock.  With the profiler
+  alone, spans write to the profiler and nothing else (no ``Tracer``, no
+  registry, ``.stats`` stays None).
+
+Transfers between host and device go through ``fetch`` and ``put``: each
+is a span carrying ``bytes`` and ``what``, so time spent waiting on the
+device is named where the host blocks, not booked to whichever span
+happens to sync first.
 
 Exports:
 
@@ -36,7 +49,16 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
-ENABLED = False                 # module-level hot-path gate — read, not called
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.profiler import TraceAnnotation
+
+from repro.obs.metrics import registry
+
+ENABLED = False                 # a Tracer is on: spans, counters, .stats
+SPANS = False                   # the span hot-path gate: ENABLED or _PROFILE
+_PROFILE = False                # the profiler was collecting at the call
 _TRACER: Optional["Tracer"] = None
 _LOCK = threading.Lock()
 
@@ -71,32 +93,44 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """A live span: records its event on exit."""
+    """A live span: opens its profiler annotation (profiler collecting) and
+    records its event on exit (tracer on)."""
 
-    __slots__ = ("tracer", "name", "track", "start", "depth")
+    __slots__ = ("tracer", "name", "track", "args", "ann", "start", "depth")
 
-    def __init__(self, tracer: "Tracer", name: str, track: Optional[str]):
+    def __init__(self, tracer: Optional["Tracer"], name: str,
+                 track: Optional[str], args: Optional[dict] = None):
         self.tracer = tracer
         self.name = name
         self.track = track
+        self.args = args
 
     def __enter__(self):
-        tl = self.tracer._tl()
-        if self.track is None:
-            self.track = tl.track
-        self.depth = len(tl.stack)
-        tl.stack.append(self.name)
-        self.start = time.perf_counter()
+        self.ann = None
+        if _PROFILE:
+            self.ann = TraceAnnotation("repro." + self.name,
+                                       **(self.args or {}))
+            self.ann.__enter__()
+        if self.tracer is not None:
+            tl = self.tracer._tl()
+            if self.track is None:
+                self.track = tl.track
+            self.depth = len(tl.stack)
+            tl.stack.append(self.name)
+            self.start = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        end = time.perf_counter()
-        tl = self.tracer._tl()
-        tl.stack.pop()
-        self.tracer._record(SpanEvent(
-            name=self.name, start=self.start - self.tracer.epoch,
-            dur=end - self.start, track=self.track, depth=self.depth,
-            tid=threading.get_ident()))
+        if self.tracer is not None:
+            end = time.perf_counter()
+            tl = self.tracer._tl()
+            tl.stack.pop()
+            self.tracer._record(SpanEvent(
+                name=self.name, start=self.start - self.tracer.epoch,
+                dur=end - self.start, track=self.track, depth=self.depth,
+                tid=threading.get_ident()))
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
         return False
 
 
@@ -254,12 +288,13 @@ class SpanSummary:
 
 # ---- module-level API (what the pipeline calls) --------------------------
 
-def span(name: str, *, track: Optional[str] = None):
-    """Open a nested span.  THE hot-path entry point: when tracing is off
-    this is one global-bool check plus returning a cached null object."""
-    if not ENABLED:
+def span(name: str, *, track: Optional[str] = None, **args):
+    """Open a nested span.  THE hot-path entry point: when spans are off
+    this is one global-bool check plus returning a cached null object.
+    ``args`` become the profiler annotation's metadata (counts, bytes)."""
+    if not SPANS:
         return _NULL_SPAN
-    return _Span(_TRACER, name, track)
+    return _Span(_TRACER, name, track, args)
 
 
 def traced(name: Optional[str] = None) -> Callable:
@@ -268,7 +303,7 @@ def traced(name: Optional[str] = None) -> Callable:
         sname = name or fn.__name__
 
         def wrapper(*args, **kwargs):
-            if not ENABLED:
+            if not SPANS:
                 return fn(*args, **kwargs)
             with _Span(_TRACER, sname, None):
                 return fn(*args, **kwargs)
@@ -292,23 +327,26 @@ def tracer() -> Optional[Tracer]:
     return _TRACER
 
 
+def _set(enabled: bool, tracer: Optional[Tracer], profile: bool) -> None:
+    """Install the gate state; the caller holds ``_LOCK``."""
+    global ENABLED, SPANS, _PROFILE, _TRACER
+    ENABLED, _TRACER, _PROFILE = enabled, tracer, profile
+    SPANS = enabled or profile
+
+
 def enable() -> Tracer:
     """Switch tracing on (idempotent); returns the active tracer."""
-    global ENABLED, _TRACER
     with _LOCK:
-        if _TRACER is None:
-            _TRACER = Tracer()
-        ENABLED = True
+        _set(True, _TRACER if _TRACER is not None else Tracer(), _PROFILE)
         return _TRACER
 
 
 def disable() -> Optional[Tracer]:
     """Switch tracing off; returns the tracer that was active (so callers
     can still export), clearing the global slot."""
-    global ENABLED, _TRACER
     with _LOCK:
-        tr, _TRACER = _TRACER, None
-        ENABLED = False
+        tr = _TRACER
+        _set(False, None, _PROFILE)
         return tr
 
 
@@ -316,36 +354,86 @@ def disable() -> Optional[Tracer]:
 def tracing(path=None):
     """``with repro.obs.tracing("trace.json"):`` — enable for the block,
     write Chrome trace JSON to ``path`` on exit, restore the prior state."""
-    global ENABLED, _TRACER
     prev_enabled, prev_tracer = ENABLED, _TRACER
     tr = enable()
     try:
         yield tr
     finally:
         with _LOCK:
-            ENABLED, _TRACER = prev_enabled, prev_tracer
+            _set(prev_enabled, prev_tracer, _PROFILE)
         if path is not None:
             tr.write_chrome(path)
 
 
 @contextlib.contextmanager
-def ensure(flag: bool):
-    """Enable tracing for the block iff ``flag`` and it is not already on —
-    the ``LUOptions(trace=True)`` plumbing.  Yields the active tracer (or
-    None).  Never disables a tracer someone outside the block owns."""
-    global ENABLED, _TRACER
-    if not flag:
-        yield _TRACER if ENABLED else None
-        return
-    if ENABLED:
-        yield _TRACER
-        return
-    tr = enable()
+def ensure(flag: bool = False):
+    """The gate every public call opens (``analyze``, ``replan``,
+    ``factorize``, ``factorize_batch``, ``solve``, ``solve_batch``).
+
+    Asks once whether a JAX profiler session is collecting; if so, spans
+    inside the block annotate the profiler's trace.  Enables tracing for
+    the block iff ``flag`` (``LUOptions(trace=True)``) and it is not
+    already on.  Yields the active tracer (or None).  Never disables a
+    tracer someone outside the block owns."""
+    outer = _PROFILE
+    profile = TraceAnnotation.is_enabled()
+    with _LOCK:
+        installed = None
+        if flag and not ENABLED:
+            installed = Tracer()
+            _set(True, installed, profile)
+        else:
+            _set(ENABLED, _TRACER, profile)
     try:
-        yield tr
+        yield _TRACER if ENABLED else None
     finally:
         with _LOCK:
             # only tear down if still the tracer we installed
-            if _TRACER is tr:
-                ENABLED = False
-                _TRACER = None
+            if installed is not None and _TRACER is installed:
+                _set(False, None, outer)
+            else:
+                _set(ENABLED, _TRACER, outer)
+
+
+# ---- host <-> device transfers -------------------------------------------
+
+def _nbytes(x) -> int:
+    return sum(int(leaf.nbytes) for leaf in jax.tree_util.tree_leaves(x))
+
+
+def fetch(x, what: str):
+    """``x`` (a device array, or a tuple of them) on the host as numpy.
+
+    A tuple's copies all start before the first one is waited on.  While
+    spans are live the transfer runs inside a ``fetch`` span with
+    ``bytes`` and ``what``: it times waiting on the device plus the copy.
+    With tracing on it also counts ``transfer.bytes_to_host``.  A numpy
+    array is already on the host: it comes back as it is, with no span."""
+    if not SPANS:
+        return jax.device_get(x) if isinstance(x, tuple) else np.asarray(x)
+    if isinstance(x, np.ndarray):
+        return x
+    nbytes = _nbytes(x)
+    with _Span(_TRACER, "fetch", None, {"bytes": nbytes, "what": what}):
+        out = jax.device_get(x) if isinstance(x, tuple) else np.asarray(x)
+    if ENABLED:
+        registry().count("transfer.bytes_to_host", nbytes)
+    return out
+
+
+def put(x, what: str) -> jax.Array:
+    """Host array ``x`` on the default device (``jnp.asarray``).  While
+    spans are live the transfer runs inside a ``put`` span with ``bytes``
+    and ``what``; with tracing on it also counts
+    ``transfer.bytes_to_device``.  A device array comes back as it is,
+    with no span."""
+    if not SPANS:
+        return jnp.asarray(x)
+    if isinstance(x, jax.Array):
+        return x
+    nbytes = int(x.nbytes)
+    with _Span(_TRACER, "put", None, {"bytes": nbytes, "what": what}):
+        out = jnp.asarray(x)
+    if ENABLED:
+        registry().count("transfer.bytes_to_device", nbytes)
+    return out

@@ -29,7 +29,6 @@ import dataclasses
 import numpy as np
 
 from repro.numeric.solve import solve_factored, solve_factored_transposed
-from repro.obs import metrics as _om
 from repro.obs import trace as _ot
 
 #: Verdict thresholds.  cond_1 beyond ~1e10 leaves <6 float64 digits for
@@ -144,9 +143,4 @@ def estimate_quality(num, a_f, factored_values: np.ndarray, *,
                                perturbed_pivots=int(perturbed_pivots),
                                verdict=_verdict(growth, cond,
                                                 int(perturbed_pivots)))
-        if _ot.ENABLED:
-            reg = _om.registry()
-            reg.gauge("robust.growth", growth if np.isfinite(growth) else -1.0)
-            reg.gauge("robust.cond_estimate",
-                      cond if np.isfinite(cond) else -1.0)
     return report

@@ -37,6 +37,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro.obs import trace as _ot
+
 
 _RING_OPS = ("add", "xor", "max")
 
@@ -190,10 +192,13 @@ def merge_fingerprint_shards(mesh: Mesh, axis: str, shards):
     ops = {"counts": "add", "hsum": "add", "hxor": "xor",
            "subdiag": "max", "seen": "max"}
     merged = ColumnFingerprints(n=n)
-    rings = {op: make_ring_allreduce(mesh, axis, op=op)
-             for op in set(ops.values())}
+    rings = {}
+    for op in set(ops.values()):
+        with _ot.span("ring_build"):
+            rings[op] = make_ring_allreduce(mesh, axis, op=op)
     for name, arr in stack.items():
-        out = np.asarray(rings[ops[name]](jnp.asarray(arr)))[0]
+        out = _ot.fetch(rings[ops[name]](_ot.put(arr, "fingerprint shards")),
+                        "merged fingerprints")[0]
         if name == "counts":
             merged.counts = out.astype(np.int64)
         elif name == "hsum":

@@ -157,6 +157,4 @@ def merge_supernodes(pattern, supernodes, model, *, threshold: float = 1.0,
             reg.count("blocking.merges", merges)
             reg.gauge("blocking.panels_before", stats.n_before)
             reg.gauge("blocking.panels_after", stats.n_after)
-            reg.gauge("blocking.pad_entries", stats.pad_entries_after)
-            reg.gauge("blocking.modeled_gain_s", stats.modeled_gain_s)
     return out, stats

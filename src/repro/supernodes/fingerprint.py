@@ -89,11 +89,13 @@ class ColumnFingerprints:
                 contributes to columns j < s < W, so truncation is lossless).
         srcs:   (G,) source ids of the label rows (repeats allowed — padding).
         """
-        if not _ot.ENABLED:
+        if not _ot.SPANS:
             return self._update(labels, srcs, offset)
         t0 = time.perf_counter()
         with _ot.span("fingerprint_update"):
             consumed = self._update(labels, srcs, offset)
+        if not _ot.ENABLED:
+            return consumed
         # analytic traffic of the column reduction: the (consumed, W) int32
         # label block read once + the three W-wide int32 partials written
         reg = _om.registry()
@@ -114,7 +116,8 @@ class ColumnFingerprints:
         kept_srcs = srcs[keep]
         self.seen[kept_srcs] = True
 
-        lab = jnp.asarray(labels)[jnp.asarray(keep, dtype=jnp.int32)]
+        lab = _ot.put(labels, "chunk labels")[jnp.asarray(keep,
+                                                          dtype=jnp.int32)]
         off = jnp.int32(offset)
         # offset-free labels: maxId, or w+1 (> any real column) when the
         # label is uninitialized / stale arena garbage
@@ -135,7 +138,7 @@ class ColumnFingerprints:
                 part = kops.column_fingerprints(rel, src_j, m1, m2, valid)
             else:
                 part = kops.column_fingerprints_ref(rel, src_j, m1, m2, valid)
-        part = np.asarray(part)
+        part = _ot.fetch(part, "fingerprint partials")
         self.counts[:w] += part[0].astype(np.int64)
         self.hsum[:w] += part[1].view(np.uint32)
         self.hxor[:w] ^= part[2].view(np.uint32)
@@ -145,8 +148,9 @@ class ColumnFingerprints:
         if np.any(has_prev):
             rows = np.flatnonzero(has_prev)
             cols = kept_srcs[rows] - 1
-            vals = np.asarray(rel[jnp.asarray(rows, jnp.int32),
-                                  jnp.asarray(cols, jnp.int32)])
+            vals = _ot.fetch(rel[jnp.asarray(rows, jnp.int32),
+                                 jnp.asarray(cols, jnp.int32)],
+                             "subdiagonal labels")
             self.subdiag[kept_srcs[rows]] = vals < cols
         return len(keep)
 

@@ -144,5 +144,4 @@ def autotune_partition(pattern, fingerprints, options, *,
             reg = _om.registry()
             reg.count("tune.candidates", len(candidates))
             reg.gauge("tune.modeled_s", report.modeled_s)
-            reg.gauge("tune.baseline_s", report.baseline_s)
     return supernodes, report

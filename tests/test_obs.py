@@ -253,6 +253,174 @@ def test_lu_options_trace_populates_stats():
     assert om.registry().get("fill.lu_nnz") > 0
 
 
+# ---- the JAX profiler: program spans on the device clock -----------------
+
+def _small_session(backend="numpy", nx=6):
+    """analyze -> factorize -> solve on a small grid; returns both results."""
+    from repro.api import LUOptions, analyze
+    from repro.sparse import grid2d_laplacian
+    from repro.sparse.numeric import generic_values
+
+    a = grid2d_laplacian(nx)
+    plan = analyze(a, LUOptions(concurrency=32, numeric_backend=backend))
+    factor = plan.factorize(generic_values(a))
+    factor.solve(np.ones(a.n))
+    return plan, factor
+
+
+def _host_events(trace_dir):
+    """{span name: [stats dict, ...]} of the ``repro.`` host events."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("repro."):
+                    out.setdefault(ev.name, []).append(dict(ev.stats))
+    return out
+
+
+def test_profiler_session_records_program_spans(tmp_path):
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        plan, factor = _small_session(backend="kernel")   # interpret mode
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(tmp_path)
+    for name in ("analyze", "fixpoint", "pattern_collect", "factorize",
+                 "panel_prepare", "panel_gemm", "panel_finish", "solve",
+                 "residual", "fetch", "put"):
+        assert "repro." + name in events, name
+    fetches = events["repro.fetch"]
+    assert all(st["bytes"] > 0 and st["what"] for st in fetches)
+    assert {"chunk counts", "chunk mask", "panel update"} <= {
+        st["what"] for st in fetches}
+    assert all(st["bytes"] > 0 for st in events["repro.put"])
+    # the profiler alone asked: nothing reaches a Tracer or the registry
+    assert plan.stats is None and factor.stats is None
+    assert om.registry().snapshot() == {
+        "counters": {}, "gauges": {}, "histograms": {}}
+    assert not ot.SPANS and not ot.ENABLED
+
+
+class _CountingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: counts the profiler
+    checks and the annotations built."""
+
+    checks = 0
+    built = []
+    collecting = True
+
+    def __init__(self, name, **args):
+        type(self).built.append(name)
+
+    @classmethod
+    def is_enabled(cls):
+        cls.checks += 1
+        return cls.collecting
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.fixture
+def counting_annotation(monkeypatch):
+    class Counting(_CountingAnnotation):
+        checks = 0
+        built = []
+    monkeypatch.setattr(ot, "TraceAnnotation", Counting)
+    return Counting
+
+
+def test_disabled_never_builds_trace_annotation(counting_annotation):
+    counting_annotation.collecting = False
+
+    def boom(self, name, **args):
+        raise AssertionError(f"annotation {name} built while disabled")
+    counting_annotation.__init__ = boom
+    _small_session()
+    assert counting_annotation.checks == 3     # analyze, factorize, solve
+
+
+def test_profiler_checked_once_per_public_call(counting_annotation):
+    from repro.api import LUOptions, analyze
+    from repro.sparse import grid2d_laplacian
+    from repro.sparse.numeric import generic_values
+
+    a = grid2d_laplacian(10)
+    plan = analyze(a, LUOptions(concurrency=32))
+    assert counting_annotation.checks == 1
+    counting_annotation.checks, counting_annotation.built[:] = 0, []
+    factor = plan.factorize(generic_values(a))
+    assert plan.n_levels > 5
+    assert counting_annotation.checks == 1
+    built = counting_annotation.built
+    assert built.count("repro.factor_level") == plan.n_levels
+    assert len(built) > 3 * plan.n_levels
+    counting_annotation.checks = 0
+    factor.solve(np.ones(a.n))
+    assert counting_annotation.checks == 1
+    # profiler-only spans leave no Tracer behind and no stats
+    assert plan.stats is None and factor.stats is None
+    assert not ot.SPANS and ot.tracer() is None
+
+
+def test_profiler_and_tracing_together_fill_both(counting_annotation):
+    from repro.api import LUOptions, analyze
+    from repro.sparse import grid2d_laplacian
+
+    plan = analyze(grid2d_laplacian(6), LUOptions(concurrency=32,
+                                                  trace=True))
+    assert plan.stats is not None and plan.stats.find("fixpoint")
+    assert "repro.fixpoint" in counting_annotation.built
+    assert om.registry().get("transfer.bytes_to_host") > 0
+    assert not ot.SPANS and not ot.ENABLED
+
+
+def test_ensure_restores_the_outer_profiler_state(counting_annotation):
+    with ot.ensure():
+        assert ot.SPANS and not ot.ENABLED and ot.tracer() is None
+        counting_annotation.collecting = False
+        with ot.ensure():
+            assert not ot.SPANS
+        assert ot.SPANS                # the outer call still annotates
+    assert not ot.SPANS
+    assert counting_annotation.checks == 2
+
+
+def test_fetch_and_put_count_bytes_only_under_tracing():
+    import jax.numpy as jnp
+
+    x = np.arange(12, dtype=np.float32).reshape(3, 4)
+    d = ot.put(x, "test")                        # spans off: no span
+    assert np.array_equal(ot.fetch(d, "test"), x)
+    a, b = ot.fetch((d, d[0]), "pair")
+    assert np.array_equal(a, x) and np.array_equal(b, x[0])
+    assert om.registry().snapshot()["counters"] == {}
+    with ot.tracing() as tr:
+        d = ot.put(x, "test")
+        assert ot.put(d, "again") is d           # already on the device
+        assert ot.fetch(x, "host") is x          # already on the host
+        ot.fetch((d, jnp.ones(2, jnp.int32)), "pair")
+    reg = om.registry()
+    assert reg.get("transfer.bytes_to_device") == x.nbytes
+    assert reg.get("transfer.bytes_to_host") == x.nbytes + 8
+    totals = tr.phase_totals()
+    assert totals["put"]["count"] == 1 and totals["fetch"]["count"] == 1
+
+
 # ---- metrics parity: single device vs 8 virtual devices ------------------
 
 _PARITY_SCRIPT = r"""
