@@ -32,6 +32,7 @@ from typing import List, Optional
 import numpy as np
 
 from repro.numeric.storage import CSCPattern
+from repro.obs import metrics as _om
 from repro.obs import trace as _ot
 from repro.supernodes.balance import PanelPartition, pack_panels
 
@@ -173,12 +174,97 @@ def build_panel_maps(store, schedule: PanelSchedule,
                      below_maps=below_maps, idx_j=idx_j, hit_j=hit_j)
 
 
+def gather_map_entries(store, schedule: PanelSchedule) -> int:
+    """Entries the strip and below maps of every panel hold together.
+
+    Ancestor ``i`` of panel j maps ``anc_rows[offs[i]:]`` and the panel's
+    rows from its diagonal on, so a panel's strips sum to ``Σ_i w_i (i+1)``
+    (ancestor widths ``w``) and its below maps to ``len(anc) * B_j``."""
+    counts = np.fromiter(map(len, schedule.ancestors), dtype=np.int64,
+                         count=schedule.n_panels)
+    if not counts.sum():
+        return 0
+    anc = np.concatenate(schedule.ancestors)
+    widths = schedule.supernodes[anc, 1] - schedule.supernodes[anc, 0]
+    rank = np.arange(len(anc)) - np.repeat(np.cumsum(counts) - counts,
+                                           counts)
+    below = np.fromiter(map(len, store.rows), dtype=np.int64,
+                        count=store.n_panels) - store.diag
+    return int(widths @ (rank + 1) + counts @ below)
+
+
+def _gather_maps_by_search(store, schedule: PanelSchedule
+                           ) -> List[Optional[PanelMaps]]:
+    """One ``local_rows`` search per (panel, ancestor) map."""
+    return [build_panel_maps(store, schedule, j)
+            for j in range(schedule.n_panels)]
+
+
+def _gather_maps_by_table(store, schedule: PanelSchedule
+                          ) -> List[Optional[PanelMaps]]:
+    """The same maps read from one dense (panel, global row) table of what
+    ``local_rows`` returns, built with one search per panel.
+
+    Per panel j, ancestor ``i`` reads the table at ``q[offs[i]:]``, where
+    ``q = concat(anc_rows, below)`` (strictly increasing: ancestor diagonal
+    rows lie under s, the below rows from s on).  All ancestors are read in
+    one gather into a flat array; each strip and below map is a view of its
+    own slice of it, so the plan holds exactly the entries the searches
+    would build."""
+    n, sn = store.n, schedule.supernodes
+    grid = np.arange(n, dtype=np.int64)
+    rank = np.empty((store.n_panels, n), dtype=np.int64)
+    member = np.zeros((store.n_panels, n), dtype=bool)
+    for k, rows in enumerate(store.rows):
+        np.minimum(np.searchsorted(rows, grid), len(rows) - 1, out=rank[k])
+        member[k, rows] = True
+    rank, member = rank.ravel(), member.ravel()
+    maps: List[Optional[PanelMaps]] = []
+    for j, anc in enumerate(schedule.ancestors):
+        if not len(anc):
+            maps.append(None)
+            continue
+        widths = sn[anc, 1] - sn[anc, 0]
+        offs = np.concatenate([[0], np.cumsum(widths)])
+        n_anc_rows = int(offs[-1])
+        anc_rows = (np.repeat(sn[anc, 0] - offs[:-1], widths)
+                    + grid[:n_anc_rows])
+        q = np.concatenate([anc_rows, store.rows[j][int(store.diag[j]):]])
+        lens = len(q) - offs[:-1]
+        flat = np.concatenate([q[o:] for o in offs[:-1].tolist()])
+        flat += np.repeat(anc * n, lens)
+        idx, hit = rank[flat], member[flat]
+        ends = np.cumsum(lens)
+        starts = ends - lens
+        mids = starts + n_anc_rows - offs[:-1]
+        cuts = zip(starts.tolist(), mids.tolist(), ends.tolist())
+        strip_maps, below_maps = [], []
+        for a, b, c in cuts:
+            strip_maps.append((idx[a:b], hit[a:b]))
+            below_maps.append((idx[b:c], hit[b:c]))
+        idx_j, hit_j = store.local_rows(j, anc_rows)
+        maps.append(PanelMaps(anc_rows=anc_rows, offs=offs,
+                              strip_maps=strip_maps, below_maps=below_maps,
+                              idx_j=idx_j, hit_j=hit_j))
+    return maps
+
+
 def build_gather_maps(store, schedule: PanelSchedule) -> List[Optional[PanelMaps]]:
     """Precompute every panel's ancestor gather/scatter maps from the packed
     row structure — the value-independent half of ``supernodal
-    ._factor_panel``, built once per analysis and replayed per factorize."""
-    return [build_panel_maps(store, schedule, j)
-            for j in range(schedule.n_panels)]
+    ._factor_panel``, built once per analysis and replayed per factorize.
+
+    Both builders give identical maps; the table costs ``n_panels * n``
+    entries, so it is built only when the maps hold at least as many."""
+    entries = gather_map_entries(store, schedule)
+    table = store.n_panels * store.n <= entries
+    build = _gather_maps_by_table if table else _gather_maps_by_search
+    maps = build(store, schedule)
+    if _ot.ENABLED:
+        reg = _om.registry()
+        reg.count("plan.gather_map_entries", entries)
+        reg.count("plan.gather_map_table", int(table))
+    return maps
 
 
 def _validate_supernodes(supernodes: np.ndarray, n: int) -> np.ndarray:

@@ -28,6 +28,8 @@ tune.candidates                 counter   partitions scored by the autotune swee
 tune.modeled_s                  gauge     modeled sweep seconds of the chosen
 transfer.bytes_to_host          counter   bytes read back by ``fetch``
 transfer.bytes_to_device        counter   bytes sent by ``put``
+plan.gather_map_entries         counter   strip + below map entries built
+plan.gather_map_table           counter   plans whose maps read the rank table
 ==============================  ========  =====================================
 
 Roofline: ``fraction_of_peak`` / ``roofline_report`` are pure functions of
